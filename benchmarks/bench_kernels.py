@@ -1,14 +1,15 @@
-"""Pallas kernel micro-benchmarks (interpret mode on CPU — correctness
-path; wall numbers are NOT TPU perf, the roofline table covers that).
-Compares each kernel's interpret-mode call against its compiled pure-jnp
+"""Pallas kernel micro-benchmarks.  Off the TPU the kernels run in
+interpret mode (a correctness path: wall numbers there are not TPU
+perf).  Compares each kernel's call against its compiled pure-jnp
 oracle to document overhead and validate at benchmark shapes.
 
 The SpMV-loop vs batched-SpMM section is the CI perf gate for the
 batched analytics layer: answering b column queries as one SpMM launch
 must beat b sequential SpMV launches (the per-query dispatch the
-gateway used to pay) by ≥ 2x at b=8.  The roofline columns model the
-TPU story: bytes/query collapse because the ELL block streams from HBM
-once per *batch* instead of once per *query*.
+gateway used to pay) by ≥ 2x at b=8.  On a TPU it also reports achieved
+HBM bandwidth against the device's published peak
+(``repro.device.peaks``); off the TPU no peak share is reported, since a
+CPU time is not a device metric.
 """
 from __future__ import annotations
 
@@ -28,8 +29,18 @@ from .common import emit, smoke, timeit, write_trajectory
 def spmm_roofline() -> None:
     """SpMV-loop vs batched SpMM at b ∈ {1, 8, 64}: wall time (interpret
     mode — dispatch-bound, which is exactly what batching removes) plus
-    the HBM-traffic roofline model (achieved GB/s vs TPU peak)."""
-    from repro.launch.roofline import HBM_BW
+    the HBM-traffic roofline model (achieved GB/s vs the TPU's peak)."""
+    from repro.device import peaks
+
+    dev = jax.devices()[0]
+    hbm_bw = (peaks(dev.device_kind).hbm_bytes_per_s
+              if dev.platform == "tpu" else None)
+
+    def roofline(gbs):
+        if hbm_bw is None:
+            return {}
+        return {"peak_gb_s": hbm_bw / 1e9,
+                "pct_peak": round(100 * gbs * 1e9 / hbm_bw, 4)}
 
     R, C, K = (1024, 1024, 4) if smoke() else (2048, 2048, 4)
     br, bc = 256, 1024
@@ -69,14 +80,10 @@ def spmm_roofline() -> None:
         speedup = t_loop / t_spmm
         emit(f"spmv_loop_b{b}", t_loop / b * 1e6,
              f"allclose={ok} gbs={gbs_loop:.3f}",
-             achieved_gb_s=round(gbs_loop, 4),
-             peak_gb_s=HBM_BW / 1e9,
-             pct_peak=round(100 * gbs_loop * 1e9 / HBM_BW, 4))
+             achieved_gb_s=round(gbs_loop, 4), **roofline(gbs_loop))
         emit(f"spmm_batched_b{b}", t_spmm / b * 1e6,
              f"speedup={speedup:.2f}x gbs={gbs_spmm:.3f}",
-             achieved_gb_s=round(gbs_spmm, 4),
-             peak_gb_s=HBM_BW / 1e9,
-             pct_peak=round(100 * gbs_spmm * 1e9 / HBM_BW, 4),
+             achieved_gb_s=round(gbs_spmm, 4), **roofline(gbs_spmm),
              speedup_vs_loop=round(speedup, 3))
         if b == 8:
             ratio_at_8 = speedup

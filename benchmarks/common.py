@@ -6,6 +6,8 @@ import json
 import os
 import time
 
+import jax
+
 _RECORDS: list = []
 
 
@@ -39,11 +41,16 @@ def emit(name: str, us_per_call: float, derived: str = "",
 def write_trajectory(bench: str) -> str:
     """Append this run's records to ``BENCH_<bench>.json`` (JSONL — one
     run object per line, so successive runs form a trajectory).  The
-    output directory defaults to cwd; override with BENCH_OUT_DIR."""
+    output directory defaults to cwd; override with BENCH_OUT_DIR.  Each
+    run names the device it ran on."""
     path = os.path.join(os.environ.get("BENCH_OUT_DIR", "."),
                         f"BENCH_{bench}.json")
+    devs = jax.devices()
     run = {"bench": bench, "unix_time": round(time.time(), 3),
-           "smoke": smoke(), "records": list(_RECORDS)}
+           "smoke": smoke(),
+           "device": {"platform": devs[0].platform,
+                      "kind": devs[0].device_kind, "count": len(devs)},
+           "records": list(_RECORDS)}
     with open(path, "a") as f:
         f.write(json.dumps(run) + "\n")
     _RECORDS.clear()

@@ -20,15 +20,21 @@ docstring for the paper artifact it reproduces):
 """
 from __future__ import annotations
 
+import sys
 import traceback
 
 
-def main() -> None:
+def main() -> int:
+    """Run every module; returns the number of modules that failed."""
+    from repro.device import enable_compile_cache
+
     from . import (bench_analytics, bench_expansion, bench_ingest,
                    bench_kernels, bench_loc, bench_lsm, bench_net,
                    bench_obs, bench_pipeline_scaling, bench_query,
                    bench_serving, bench_stream)
+    enable_compile_cache()
     print("name,us_per_call,derived")
+    failed = 0
     for mod in (bench_loc, bench_expansion, bench_query, bench_ingest,
                 bench_lsm, bench_net, bench_analytics, bench_kernels,
                 bench_serving, bench_stream, bench_obs,
@@ -38,7 +44,9 @@ def main() -> None:
         except Exception:
             print(f"{mod.__name__},FAILED,")
             traceback.print_exc()
+            failed += 1
+    return failed
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(1 if main() else 0)

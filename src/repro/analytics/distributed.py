@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..core.sparse import COO
 
@@ -45,9 +44,9 @@ def degree_sharded(m: COO, mesh: Mesh, axis: str = "data") -> jax.Array:
     spec = P(axis, None)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def _deg(rows, cols, vals):
         rows, cols, vals = rows[0], cols[0], vals[0]
         live = (rows < n_rows).astype(vals.dtype)
@@ -60,14 +59,19 @@ def degree_sharded(m: COO, mesh: Mesh, axis: str = "data") -> jax.Array:
 def spmv_t_sharded(m: COO, x: jax.Array, mesh: Mesh,
                    axis: str = "data") -> jax.Array:
     """y[j] = Σ_i m[i,j]·x[i], nnz-sharded with psum (PageRank inner op)."""
-    n_shards = mesh.shape[axis]
-    sh = shard_coo(m, n_shards)
-    n_rows, n_cols = m.shape
+    return _spmv_t_shards(shard_coo(m, mesh.shape[axis]), x, mesh, axis)
+
+
+def _spmv_t_shards(sh: COO, x: jax.Array, mesh: Mesh, axis: str
+                   ) -> jax.Array:
+    """:func:`spmv_t_sharded` over a COO already split by
+    :func:`shard_coo`."""
+    n_rows, n_cols = sh.shape
     spec = P(axis, None)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec, P()),
-        out_specs=P(), check_rep=False)
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec, P()),
+        out_specs=P(), check_vma=False)
     def _spmv(rows, cols, vals, xv):
         rows, cols, vals = rows[0], cols[0], vals[0]
         safe = jnp.minimum(rows, n_rows - 1)
@@ -94,16 +98,27 @@ def pagerank_sharded(adj: COO, mesh: Mesh, num_iters: int = 20,
     else:
         p = jnp.maximum(personalize.astype(jnp.float32), 0.0)
         p = p / jnp.maximum(jnp.sum(p), 1e-30)
+    return _pagerank(adj, p, mesh=mesh, num_iters=num_iters,
+                     damping=damping, axis=axis)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("mesh", "num_iters", "damping", "axis"))
+def _pagerank(adj: COO, p: jax.Array, *, mesh: Mesh, num_iters: int,
+              damping: float, axis: str) -> jax.Array:
+    """The whole power iteration as one program: the shard_map bodies
+    are traced once, not rebuilt (and recompiled) every iteration."""
+    sh = shard_coo(adj, mesh.shape[axis])
     out_deg_w = spmv_weighted_rowsum(adj, mesh, axis)
     inv_deg = jnp.where(out_deg_w > 0, 1.0 / jnp.maximum(out_deg_w, 1e-30),
                         0.0)
-    rank = p
-    for _ in range(num_iters):
-        contrib = rank * inv_deg
-        spread = spmv_t_sharded(adj, contrib, mesh, axis)
+
+    def step(_, rank):
+        spread = _spmv_t_shards(sh, rank * inv_deg, mesh, axis)
         dangling = jnp.sum(jnp.where(out_deg_w > 0, 0.0, rank))
-        rank = (1 - damping) * p + damping * (spread + dangling * p)
-    return rank
+        return (1 - damping) * p + damping * (spread + dangling * p)
+
+    return jax.lax.fori_loop(0, num_iters, step, p)
 
 
 def pagerank_table(T, mesh: Mesh | None = None, num_iters: int = 20,
@@ -162,8 +177,8 @@ def spmv_weighted_rowsum(m: COO, mesh: Mesh, axis: str = "data"
     spec = P(axis, None)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec),
-        out_specs=P(), check_rep=False)
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=P(), check_vma=False)
     def _rs(rows, cols, vals):
         rows, vals = rows[0], vals[0]
         safe = jnp.minimum(rows, n_rows - 1)

@@ -1,9 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On a real TPU pass ``interpret=False`` (the default flips on backend);
-this container is CPU-only, so interpret=True executes the kernel bodies
-in Python for correctness validation while the pure-JAX fallbacks serve
-the compiled dry-run path.
+Every kernel's ``interpret`` follows the backend: compiled (Mosaic) on
+a TPU, the Pallas interpreter elsewhere, where it validates the kernel
+bodies while the pure-JAX fallbacks serve the compiled path.
 """
 from __future__ import annotations
 

@@ -14,6 +14,7 @@ Grid: (segment tiles, nnz blocks); the nnz-block dimension is sequential
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +41,7 @@ def _segsum_kernel(ids_ref, vals_ref, out_ref, *, block_seg: int):
     onehot = (cols == local[:, None]).astype(jnp.float32)
     # (1, Bn) @ (Bn, S_tile) on the MXU
     out_ref[...] += jnp.dot(vals[None, :], onehot,
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)[0]
 
 
@@ -48,9 +50,12 @@ def _segsum_kernel(ids_ref, vals_ref, out_ref, *, block_seg: int):
 def segsum(ids: jax.Array, vals: jax.Array, num_segments: int,
            block_nnz: int = DEFAULT_BLOCK_NNZ,
            block_seg: int = DEFAULT_BLOCK_SEG,
-           interpret: bool = True) -> jax.Array:
+           interpret: Optional[bool] = None) -> jax.Array:
     """out[s] = Σ_{i: ids[i]==s} vals[i].  ids sorted (not required for
-    correctness — only for TPU memory locality)."""
+    correctness — only for TPU memory locality).  ``interpret=None``
+    compiles on TPU and interprets elsewhere."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     nnz = ids.shape[0]
     block_nnz = min(block_nnz, nnz)
     pad = (-nnz) % block_nnz
@@ -99,6 +104,7 @@ def _windowed_kernel(starts_ref, ids_ref, vals_ref, zeros_ref, out_ref, *,
     cols = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], block_seg), 1)
     onehot = (cols == local[:, None]).astype(jnp.float32)
     out_ref[...] += jnp.dot(vals[None, :], onehot,
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)[0]
 
 
@@ -107,7 +113,7 @@ def _windowed_kernel(starts_ref, ids_ref, vals_ref, zeros_ref, out_ref, *,
 def segsum_windowed(ids: jax.Array, vals: jax.Array, num_segments: int,
                     block_nnz: int = DEFAULT_BLOCK_NNZ,
                     block_seg: int = DEFAULT_BLOCK_SEG,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """Sorted-ids segmented sum, windowed (§Perf kernel iteration).
 
     The baseline kernel's one-hot matmul does O(nnz · n_seg) MXU work
@@ -117,6 +123,8 @@ def segsum_windowed(ids: jax.Array, vals: jax.Array, num_segments: int,
     (XLA segment_sum over the rare entries whose block spans > 2 tiles).
     """
     from jax.experimental.pallas import tpu as pltpu
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     nnz = ids.shape[0]
     block_nnz = min(block_nnz, nnz)
     pad = (-nnz) % block_nnz

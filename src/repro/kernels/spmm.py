@@ -57,8 +57,11 @@ def _spmm_ell_kernel(cols_ref, vals_ref, x_ref, out_ref, *,
         onehot = (iota == local[:, k][:, None]).astype(jnp.float32)
         # the gather matmul is shared by all B columns of X — this is
         # where batching beats the SpMV loop: one (BR, bc) @ (bc, B)
-        # instead of B separate (bc, 1) products
-        gathered = jnp.dot(onehot, x, preferred_element_type=jnp.float32)
+        # instead of B separate (bc, 1) products.  HIGHEST keeps x in
+        # f32 on the MXU (the default contracts in one bf16 pass, which
+        # rounds x to 8 mantissa bits; the one-hot side is exact).
+        gathered = jnp.dot(onehot, x, precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
         if ring == "plus_times":
             acc = acc + vals[:, k][:, None] * gathered
         else:                        # max_times

@@ -1,4 +1,5 @@
-"""Pallas TPU kernel: ELL-format semiring SpMV (PageRank / background model).
+"""ELL-format semiring SpMV (PageRank / background model) and the
+host-side CSR→ELL pack shared by the SpMV and SpMM kernels.
 
 CSR's per-row ragged nnz is hostile to the MXU; the TPU adaptation packs
 rows to ELL (fixed ``k_max`` nnz per row, zero-padded — D4M incidence
@@ -8,11 +9,11 @@ kernel is dense systolic work:
 
     y[r] ⊕= Σ_k vals[r,k] ⊗ (onehot(cols[r,k]) @ x_tile)
 
-Grid: (row blocks, col tiles); col-tile dimension is sequential so the
-VMEM accumulator is race-free.  plus_times and max_times semirings; for
-max_times the accumulator starts at -inf and padding slots are masked,
-so signed products reduce correctly (empty rows resolve to 0, the
-sparse no-entry convention).
+:func:`spmv_ell` is the ``b = 1`` column of
+:func:`~repro.kernels.spmm.spmm_ell`: ``x`` rides as an ``(n_cols, 1)``
+block, so every operand keeps the 2-D layout Mosaic tiles.  (Mosaic
+tiles a 1-D f32 array in 1024-element units, so a 1-D block of
+``block_rows`` = 256 does not compile.)
 
 ``interpret`` auto-selects by backend: compiled on TPU, interpreter
 everywhere else (the kernel targets Mosaic; CPU/GPU runs validate
@@ -26,47 +27,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
-
-def _spmv_ell_kernel(cols_ref, vals_ref, x_ref, out_ref, *,
-                     block_cols: int, ring: str):
-    ct = pl.program_id(1)
-
-    @pl.when(ct == 0)
-    def _init():
-        if ring == "plus_times":
-            out_ref[...] = jnp.zeros_like(out_ref)
-        else:                    # max_times identity is -inf, not 0 —
-            # a 0 floor would silently clamp negative products
-            out_ref[...] = jnp.full_like(out_ref, -jnp.inf)
-
-    cols = cols_ref[...]                         # (BR, Kmax) int32
-    vals = vals_ref[...].astype(jnp.float32)     # (BR, Kmax)
-    x = x_ref[...].astype(jnp.float32)           # (block_cols,)
-    base = ct * block_cols
-    local = cols - base
-    br, kmax = cols.shape
-    acc = out_ref[...]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (br, block_cols), 1)
-    for k in range(kmax):            # Kmax is small and static — unrolled
-        onehot = (iota == local[:, k][:, None]).astype(jnp.float32)
-        gathered = jnp.dot(onehot, x[:, None],
-                           preferred_element_type=jnp.float32)[:, 0]
-        if ring == "plus_times":
-            acc = acc + vals[:, k] * gathered
-        else:                        # max_times
-            # padding cols are -1, so local < 0 on every tile — the
-            # mask excludes both padding and out-of-tile slots
-            hit = (local[:, k] >= 0) & (local[:, k] < block_cols)
-            acc = jnp.where(hit, jnp.maximum(acc, vals[:, k] * gathered),
-                            acc)
-    if ring != "plus_times":
-        # last col tile: rows with no entries anywhere stay at the
-        # -inf identity — resolve them to 0 (sparse no-entry value)
-        is_last = ct == pl.num_programs(1) - 1
-        acc = jnp.where(is_last & jnp.isneginf(acc), 0.0, acc)
-    out_ref[...] = acc
+from .spmm import spmm_ell
 
 
 class EllOverflowError(ValueError):
@@ -134,29 +96,6 @@ def spmv_ell(ecols: jax.Array, evals: jax.Array, x: jax.Array,
     ``interpret=None`` (default) compiles on TPU and interprets on other
     backends; pass an explicit bool to force either mode.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    n_rows, _ = ecols.shape
-    n_cols = x.shape[0]
-    rpad = (-n_rows) % block_rows
-    cpad = (-n_cols) % block_cols
-    if rpad:
-        ecols = jnp.pad(ecols, ((0, rpad), (0, 0)), constant_values=-1)
-        evals = jnp.pad(evals, ((0, rpad), (0, 0)))
-    if cpad:
-        x = jnp.pad(x, (0, cpad))
-    grid = ((n_rows + rpad) // block_rows, (n_cols + cpad) // block_cols)
-    out = pl.pallas_call(
-        functools.partial(_spmv_ell_kernel, block_cols=block_cols,
-                          ring=ring),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, ecols.shape[1]), lambda r, c: (r, 0)),
-            pl.BlockSpec((block_rows, evals.shape[1]), lambda r, c: (r, 0)),
-            pl.BlockSpec((block_cols,), lambda r, c: (c,)),
-        ],
-        out_specs=pl.BlockSpec((block_rows,), lambda r, c: (r,)),
-        out_shape=jax.ShapeDtypeStruct((n_rows + rpad,), jnp.float32),
-        interpret=interpret,
-    )(ecols, evals, x)
-    return out[:n_rows]
+    return spmm_ell(ecols, evals, x[:, None], block_rows=block_rows,
+                    block_cols=block_cols, ring=ring,
+                    interpret=interpret)[:, 0]

@@ -16,20 +16,12 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=(Auto, ...)`` when this JAX has explicit axis types
-    (>= 0.5); empty on older releases, where ``jax.make_mesh`` neither
-    accepts the kwarg nor needs it (every axis is implicitly auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh(shape, axes, **kw):
-    """Version-guarded ``jax.make_mesh``: every axis auto-sharded,
-    portable across the JAX 0.5 ``AxisType`` API change."""
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)), **kw)
+    """``jax.make_mesh`` with every axis auto-sharded (the compiler
+    places collectives; no explicit-sharding annotations needed)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

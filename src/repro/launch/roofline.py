@@ -10,7 +10,7 @@ Reads results/dryrun/<cell>.json and derives, per (arch × shape × mesh):
 FLOPs/bytes, so the formulas divide by per-chip peaks directly — the
 "/ chips" of the global-numbers formulation is already applied.)
 
-Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware: TPU v5e, peaks from the ``repro.device.PEAKS`` table.
 Multi-pod 'pod' axis collectives ride DCN (~6.25 GB/s effective); the
 per-op HLO doesn't label medium, so the collective term uses ICI bw and
 the DCN adjustment is discussed qualitatively where it matters.
@@ -29,11 +29,14 @@ import os
 from typing import Optional
 
 from ..configs import ARCHS, get_config
+from ..device import peaks
 from ..models.config import shape_by_name
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # B/s
-LINK_BW = 50e9             # B/s ICI per link
+# the dry-run compiles for a described v5e pod
+_V5E = peaks("TPU v5 lite")
+PEAK_FLOPS = _V5E.bf16_flops
+HBM_BW = _V5E.hbm_bytes_per_s
+LINK_BW = _V5E.ici_link_bytes_per_s
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")

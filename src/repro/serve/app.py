@@ -122,7 +122,7 @@ class Gateway:
             if getattr(stream_analytics, "_table", None) is None:
                 stream_analytics.attach(self.table)
             stream_analytics.start()
-        self._httpd: Optional[ThreadingHTTPServer] = None
+        self._httpd: Optional[_Server] = None
         self._thread: Optional[threading.Thread] = None
         self.address: Optional[str] = None
 
@@ -147,11 +147,7 @@ class Gateway:
         class Handler(_GatewayHandler):
             gateway = gw
 
-        self._httpd = ThreadingHTTPServer((host, port), Handler)
-        self._httpd.daemon_threads = True
-        # never join request threads on close: a live SSE stream would
-        # stall shutdown until its client went away
-        self._httpd.block_on_close = False
+        self._httpd = _Server((host, port), Handler)
         self.address = f"{host}:{self._httpd.server_address[1]}"
         self._thread = threading.Thread(
             target=self._httpd.serve_forever,
@@ -267,6 +263,17 @@ class Gateway:
         except AsyncWriterError as e:
             raise HTTPError(500, f"backend writer failed: {e}")
         return 200, out, {}
+
+
+class _Server(ThreadingHTTPServer):
+    daemon_threads = True
+    # never join request threads on close: a live SSE stream would
+    # stall shutdown until its client went away
+    block_on_close = False
+    # socketserver listens with a backlog of 5: a burst of more
+    # concurrent clients than that (a coalescer wave) has its extra
+    # connections wait out a 1 s SYN retransmit
+    request_queue_size = 128
 
 
 class _GatewayHandler(BaseHTTPRequestHandler):
@@ -389,6 +396,7 @@ def main(argv=None) -> None:
     import signal
 
     from ..db import DB
+    from ..device import enable_compile_cache
 
     p = argparse.ArgumentParser(description=main.__doc__)
     p.add_argument("--host", default="127.0.0.1")
@@ -423,6 +431,7 @@ def main(argv=None) -> None:
     args = p.parse_args(argv)
     if not args.token:
         p.error("at least one --token TOKEN:TENANT is required")
+    enable_compile_cache()
 
     T = DB("Tedge", "TedgeT", "TedgeDeg", backend=args.backend,
            n_instances=args.n_instances, path=args.path)

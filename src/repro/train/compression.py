@@ -22,7 +22,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _quantized_mean(g: jax.Array, axis: str) -> jax.Array:
@@ -46,10 +45,10 @@ def compressed_pod_mean(grads, mesh: Mesh, axis: str = "pod"):
 
     def one(g):
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=P(*(None,) * g.ndim),
             out_specs=P(*(None,) * g.ndim),
-            check_rep=False)
+            check_vma=False)
         def _reduce(x):
             return _quantized_mean(x, axis)
         return _reduce(g)

@@ -10,6 +10,7 @@ import sys
 import textwrap
 
 import jax
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -154,6 +155,27 @@ class TestDistributedAnalytics:
                              capture_output=True, text=True, timeout=600)
         assert out.returncode == 0, out.stderr[-2000:]
         assert "SHARDED_ANALYTICS_OK" in out.stdout
+
+
+class TestPagerankProgram:
+    def test_power_iteration_compiles_once(self):
+        """The whole power iteration is one jitted program: a second job
+        over the same adjacency shape reuses it instead of rebuilding
+        and recompiling the shard_map bodies every iteration."""
+        import jax.numpy as jnp
+        from repro.analytics import distributed as D
+        from repro.core.sparse import COO
+        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        rng = np.random.default_rng(0)
+        n, nnz = 97, 1001       # a shape no other test compiles
+        m = COO.from_numpy(rng.integers(0, n, nnz), rng.integers(0, n, nnz),
+                           np.ones(nnz, np.float32), (n, n))
+        before = D._pagerank._cache_size()
+        a = D.pagerank_sharded(m, mesh, num_iters=7)
+        b = D.pagerank_sharded(m.astype(jnp.float32), mesh, num_iters=7)
+        assert D._pagerank._cache_size() == before + 1
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(float(jnp.sum(a)), 1.0, rtol=1e-5)
 
 
 class TestPodFsdp:

@@ -5,6 +5,9 @@ and concurrent mixed load: reader threads hammering cached queries while
 the WriterPool ingests, with a rate-limited tenant never blocking an
 admitted one."""
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
 import http.client
@@ -402,3 +405,49 @@ class TestMixedLoad:
             stop.set()
             t.join()
             pool.flush()
+
+    def test_concurrent_wave_lands_in_one_batch(self, capture):
+        """More concurrent clients than socketserver's default listen
+        backlog (5): every connection is accepted at once, so the whole
+        wave reaches the coalescer inside one window."""
+        g = make_gateway(capture, coalesce_window=0.5)
+        try:
+            cols = sorted({c for c in capture.col.tolist()
+                           if c.startswith("ip.dst|")})[:12]
+            gate = threading.Barrier(len(cols))
+            status = []
+
+            def reader(c):
+                gate.wait()
+                status.append(get(g, f"/v1/scan?axis=col&keys={c},")[0])
+
+            threads = [threading.Thread(target=reader, args=(c,))
+                       for c in cols]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+            assert status == [200] * len(cols)
+            st = g.coalescer.stats()
+            assert st["n_batches"] == 1 and st["max_batch"] == len(cols)
+        finally:
+            g.stop()
+
+
+def test_served_path_imports_leave_xla_flags_alone():
+    """launch.dryrun/calibrate/hillclimb rewrite XLA_FLAGS when imported;
+    nothing the gateway, the stores or the analytics import may pull
+    them in."""
+    code = ("import os, sys; before = os.environ.get('XLA_FLAGS'); "
+            "import repro.serve, repro.db, repro.core, repro.analytics, "
+            "repro.analytics.distributed, repro.stream, repro.pipeline, "
+            "repro.kernels.spmm, repro.kernels.spmv, repro.device; "
+            "print(os.environ.get('XLA_FLAGS') == before, "
+            "sorted(m for m in sys.modules if m.startswith('repro.launch')))")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run([sys.executable, "-c", code],
+                         env={**os.environ, "PYTHONPATH": src},
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "True []"

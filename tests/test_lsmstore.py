@@ -384,10 +384,10 @@ from _hyp import given, settings, st  # hypothesis, skipping when absent
 
 class TestLSMProperties:
     @settings(max_examples=15, deadline=None)
-    @given(st.lists(st.tuples(st.integers(0, 15), st.integers(0, 6),
-                              st.integers(0, 3)),
-                    min_size=1, max_size=60),
-           st.integers(1, 40))
+    @given(trip=st.lists(st.tuples(st.integers(0, 15), st.integers(0, 6),
+                                   st.integers(0, 3)),
+                         min_size=1, max_size=60),
+           limit=st.integers(1, 40))
     def test_random_triples_agree_with_edgestore(self, trip, limit,
                                                  tmp_path_factory):
         d = str(tmp_path_factory.mktemp("lsm"))

@@ -326,3 +326,37 @@ class TestStandaloneServer:
         s = LSMStore(str(tmp_path / "shard0"))
         assert s.n_entries == 2
         s.close()
+
+    def test_standalone_shard_never_starts_a_jax_backend(self):
+        """A shard process on the chip host must not take the TPU from
+        the gateway there: serving puts and scans through the CLI entry
+        point initializes no JAX backend."""
+        code = """
+import os, signal, socket, threading
+from jax._src import xla_bridge
+from repro.db import ShardClient, netstore
+sock = socket.socket(); sock.bind(("127.0.0.1", 0))
+port = sock.getsockname()[1]; sock.close()
+seen = {}
+def client():
+    c = ShardClient(f"127.0.0.1:{port}")
+    for _ in range(500):
+        try:
+            c.ping(); break
+        except OSError:
+            threading.Event().wait(0.02)
+    c.put_triples(["p1", "p2"], ["ip.dst|a", "ip.dst|b"], ["1", "1"])
+    seen["cells"] = sum(1 for _ in c.scan_everything())
+    seen["deg"] = c.degree("ip.dst|a")
+    c.close()
+    os.kill(os.getpid(), signal.SIGTERM)
+threading.Thread(target=client, daemon=True).start()
+netstore.main(["--port", str(port)])
+print(seen["cells"], seen["deg"], xla_bridge.backends_are_initialized())
+"""
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        out = subprocess.run([sys.executable, "-c", code],
+                             env={**os.environ, "PYTHONPATH": src},
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.split()[-3:] == ["2", "1.0", "False"]
