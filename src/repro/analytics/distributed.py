@@ -19,6 +19,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.sparse import COO
+from ..device import count_h2d
+from ..obs.trace import stage
 
 
 def shard_coo(m: COO, n_shards: int) -> COO:
@@ -125,13 +127,15 @@ def pagerank_table(T, mesh: Mesh | None = None, num_iters: int = 20,
                    src_field: str = "ip.src", dst_field: str = "ip.dst",
                    sep: str = "|", axis: str = "data",
                    personalize: dict | None = None, reverse: bool = False,
-                   damping: float = 0.85) -> tuple[np.ndarray, jax.Array]:
+                   damping: float = 0.85) -> tuple[np.ndarray, np.ndarray]:
     """PageRank served straight from the database binding.
 
     Queries the src/dst column blocks through the :class:`DBTable`
     selection grammar (pushed-down transpose-table scans), builds the
     host adjacency, then runs the mesh-sharded PageRank on the device
-    payload.  Returns ``(node_keys, ranks)`` aligned by index.
+    payload.  Returns ``(node_keys, ranks)`` aligned by index, the ranks
+    a float32 host array.  The three phases are the stages
+    ``analytics.pagerank.scan``, ``.adjacency`` and ``.device``.
 
     ``T`` may equally be an in-memory incidence :class:`Assoc` (a
     streaming window slice) — anything speaking the selection grammar.
@@ -141,12 +145,16 @@ def pagerank_table(T, mesh: Mesh | None = None, num_iters: int = 20,
     MicroRCA root-cause direction.
     """
     from ..core import graph
+    from ..core.expr import eval_batch
 
-    E = T[:, f"{src_field}{sep}*,"] + T[:, f"{dst_field}{sep}*,"]
-    adj = graph.square(graph.adjacency(
-        E, src_field=src_field, dst_field=dst_field, sep=sep))
+    with stage("analytics.pagerank.scan"):
+        src, dst = eval_batch([T[:, f"{src_field}{sep}*,"],
+                               T[:, f"{dst_field}{sep}*,"]])
+    with stage("analytics.pagerank.adjacency"):
+        adj = graph.square(graph.adjacency(
+            src + dst, src_field=src_field, dst_field=dst_field, sep=sep))
     if adj.nnz == 0:
-        return np.empty((0,), dtype=str), jnp.zeros((0,), jnp.float32)
+        return np.empty((0,), dtype=str), np.zeros((0,), np.float32)
     if reverse:
         adj = adj.T
     p = None
@@ -159,12 +167,19 @@ def pagerank_table(T, mesh: Mesh | None = None, num_iters: int = 20,
         if w.sum() <= 0:            # no seed present — uniform restart
             p = None
         else:
-            p = jnp.asarray(w)
+            p = w
     if mesh is None:
         mesh = Mesh(np.asarray(jax.devices()), (axis,))
-    ranks = pagerank_sharded(adj.device_coo(jnp.float32), mesh,
-                             num_iters=num_iters, axis=axis,
-                             personalize=p, damping=damping)
+    # upload, the whole power iteration, and the ranks back on the host
+    with stage("analytics.pagerank.device", nnz=adj.nnz):
+        coo = adj.device_coo(jnp.float32)
+        count_h2d("pagerank", coo.rows, coo.cols, coo.vals)
+        if p is not None:
+            p = jnp.asarray(p)
+            count_h2d("pagerank", p)
+        ranks = np.asarray(pagerank_sharded(
+            coo, mesh, num_iters=num_iters, axis=axis, personalize=p,
+            damping=damping))
     return adj.row, ranks
 
 

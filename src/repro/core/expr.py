@@ -36,7 +36,8 @@ import numpy as np
 from . import keys as K
 from . import sparse as S
 from ..obs.metrics import REGISTRY as _REGISTRY
-from ..obs.trace import span as _span
+from ..device import count_h2d as _count_h2d
+from ..obs.trace import span as _span, stage as _stage
 from .assoc import Assoc
 
 # nnz at which reductions/matvecs move to the device path; small payloads
@@ -64,32 +65,6 @@ _KERNEL_COUNTERS = {
     "spmv": _KERNEL_LAUNCH_FAMILY.labels(kernel="spmv"),
     "spmm": _KERNEL_LAUNCH_FAMILY.labels(kernel="spmm"),
 }
-
-
-class _LaunchView:
-    """Read-only mapping over the launch counters — the compatibility
-    shim for code that indexed the old ``KERNEL_LAUNCHES`` dict."""
-
-    def __getitem__(self, k: str) -> int:
-        return _KERNEL_COUNTERS[k].value
-
-    def __iter__(self):
-        return iter(_KERNEL_COUNTERS)
-
-    def __len__(self):
-        return len(_KERNEL_COUNTERS)
-
-    def keys(self):
-        return _KERNEL_COUNTERS.keys()
-
-    def items(self):
-        return [(k, c.value) for k, c in _KERNEL_COUNTERS.items()]
-
-    def __repr__(self):
-        return f"KERNEL_LAUNCHES{dict(self.items())!r}"
-
-
-KERNEL_LAUNCHES = _LaunchView()
 
 
 def launch_counts() -> dict:
@@ -579,7 +554,7 @@ def _device_spmm_dev(asm, X):
     when enabled (same ``USE_PALLAS_SPMV`` switch as the matvec path,
     the env now covers SpMM), COO segment reduction otherwise."""
     import jax.numpy as jnp
-    with _span("kernel.spmm", nnz=asm.nnz, b=int(X.shape[1])):
+    with _stage("kernel.spmm", nnz=asm.nnz, b=int(X.shape[1])):
         _KERNEL_COUNTERS["spmm"].inc()
         if USE_PALLAS_SPMV:
             from ..kernels import spmm as kspmm
@@ -616,6 +591,7 @@ def _device_matmul_chain(mats) -> Optional[Assoc]:
     y_keys = vec.row                    # sorted key dictionary
     y = jnp.asarray(np.asarray(vec._numeric_sm().todense()).ravel(),
                     jnp.float32)
+    _count_h2d("dense", y)
     for F in reversed(factors):
         inner = np.intersect1d(F.col, y_keys)
         if inner.size == 0:
@@ -623,8 +599,9 @@ def _device_matmul_chain(mats) -> Optional[Assoc]:
             y = jnp.zeros(F.row.shape[0], jnp.float32)
             continue
         fsm = F._onto(F.row, inner)
-        idx = np.searchsorted(y_keys, inner)    # inner ⊆ y_keys, sorted
-        y = _device_spmv_dev(fsm, jnp.take(y, jnp.asarray(idx)))
+        idx = jnp.asarray(np.searchsorted(y_keys, inner))   # inner ⊆ y_keys
+        _count_h2d("dense", idx)
+        y = _device_spmv_dev(fsm, jnp.take(y, idx))
         y_keys = F.row
     yv = np.asarray(y, dtype=np.float64)        # single host transfer
     sm = S.scipy_from_triples(
@@ -671,7 +648,7 @@ def eval_batch(exprs) -> list:
     such members are simply excluded from the fused prefetch.
     """
     nodes = [LazyAssoc.wrap(x) for x in exprs]
-    with _span("planner.eval_batch", n=len(nodes)):
+    with _stage("planner.eval_batch", n=len(nodes)):
         ex = _Executor()
         plans = [n if n._value is not None else _optimize(n) for n in nodes]
         live = [p for n, p in zip(nodes, plans) if n._value is None]
@@ -789,23 +766,32 @@ def _device_matmul_chain_multi(factors, vecs) -> Optional[list]:
         idx = np.searchsorted(y_keys, v.row)    # v.row ⊆ y_keys, sorted
         X[idx, j] = np.asarray(v._numeric_sm().todense()).ravel()
     Y = jnp.asarray(X)
+    _count_h2d("dense", Y)
     for F in reversed(factors):
-        inner = np.intersect1d(F.col, y_keys)
-        if inner.size == 0:
-            y_keys = F.row
-            Y = jnp.zeros((F.row.shape[0], b), jnp.float32)
-            continue
-        fsm = F._onto(F.row, inner)
-        idx = np.searchsorted(y_keys, inner)
-        Y = _device_spmm_dev(fsm, jnp.take(Y, jnp.asarray(idx), axis=0))
+        # key remap: the factor's columns onto the keys the vector holds
+        with _stage("planner.eval_batch.remap", nnz=F.nnz):
+            inner = np.intersect1d(F.col, y_keys)
+            if inner.size == 0:
+                y_keys = F.row
+                Y = jnp.zeros((F.row.shape[0], b), jnp.float32)
+                continue
+            fsm = F._onto(F.row, inner)
+            idx = np.searchsorted(y_keys, inner)
+        idx = jnp.asarray(idx)
+        _count_h2d("dense", idx)
+        Y = _device_spmm_dev(fsm, jnp.take(Y, idx, axis=0))
         y_keys = F.row
-    Yh = np.asarray(Y, dtype=np.float64)        # single host transfer
+    # waits for the device, then the single device-to-host copy
+    with _stage("planner.eval_batch.fetch", rows=int(Y.shape[0])):
+        Yh = np.asarray(Y, dtype=np.float64)
     outs = []
-    for j, v in enumerate(vecs):
-        col = Yh[:, j]
-        sm = S.scipy_from_triples(
-            np.arange(col.shape[0]), np.zeros(col.shape[0], np.int64),
-            col, (col.shape[0], 1))
-        sm.eliminate_zeros()
-        outs.append(Assoc._from_parts(y_keys, v.col, None, sm)._compact())
+    with _stage("planner.eval_batch.compact", b=b):
+        for j, v in enumerate(vecs):
+            col = Yh[:, j]
+            sm = S.scipy_from_triples(
+                np.arange(col.shape[0]), np.zeros(col.shape[0], np.int64),
+                col, (col.shape[0], 1))
+            sm.eliminate_zeros()
+            outs.append(
+                Assoc._from_parts(y_keys, v.col, None, sm)._compact())
     return outs

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..device import count_h2d
 from . import semiring as sr
 
 Array = jax.Array
@@ -228,6 +229,8 @@ def scipy_from_triples(rows, cols, vals, shape):
 def coo_from_scipy(m) -> COO:
     m = m.tocoo()
     order = np.lexsort((m.col, m.row))
-    return COO(jnp.asarray(m.row[order], jnp.int32),
-               jnp.asarray(m.col[order], jnp.int32),
-               jnp.asarray(m.data[order]), m.shape)
+    coo = COO(jnp.asarray(m.row[order], jnp.int32),
+              jnp.asarray(m.col[order], jnp.int32),
+              jnp.asarray(m.data[order]), m.shape)
+    count_h2d("coo", coo.rows, coo.cols, coo.vals)
+    return coo

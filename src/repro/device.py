@@ -1,15 +1,65 @@
-"""The accelerator side of a run: the persistent compile cache and the
-published peaks of each device kind.
+"""The accelerator side of a run: the persistent compile cache, the
+counters of what crosses to the device, and the published peaks of each
+device kind.
 
-Nothing here runs at import.  Entry points (``chip_smoke.py``,
+Nothing here touches ``jax`` at import.  Entry points (``chip_smoke.py``,
 ``python -m repro.serve``, ``python -m benchmarks.run``) call
-:func:`enable_compile_cache` once before their first compile.
+:func:`enable_compile_cache` once before their first compile; the
+gateway calls :func:`count_compiles`.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
+import threading
 from pathlib import Path
+
+from .obs.metrics import REGISTRY
+
+_H2D_BYTES = REGISTRY.counter(
+    "repro_h2d_bytes_total",
+    "Bytes handed to the device by the served paths, at their size on "
+    "the device", labels=("site",))
+# the sites are fixed; the family holds children weakly, so pin them
+_H2D = {site: _H2D_BYTES.labels(site=site)
+        for site in ("coo", "dense", "pagerank")}
+_COMPILES = REGISTRY.counter(
+    "repro_xla_compiles_total",
+    "XLA programs built by this process: compiled, or loaded from the "
+    "persistent compile cache")
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles_lock = threading.Lock()
+_compiles_watched = False
+
+
+def count_h2d(site: str, *arrays) -> None:
+    """Count ``arrays``, just placed on the device, as bytes handed over
+    at ``site`` (``coo``, ``dense`` or ``pagerank``)."""
+    _H2D[site].inc(sum(int(a.nbytes) for a in arrays))
+
+
+def _on_compile(event: str, secs: float, **_) -> None:
+    if event == _COMPILE_EVENT:
+        _COMPILES.inc()
+
+
+def count_compiles() -> None:
+    """Count every XLA program this process builds (JAX's
+    ``backend_compile_duration`` event: a compile, or a load from the
+    persistent cache) in ``repro_xla_compiles_total``.  Idempotent: JAX's
+    listeners cannot be removed, so one is registered once."""
+    global _compiles_watched
+    with _compiles_lock:
+        if _compiles_watched:
+            return
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _compiles_watched = True
+
+
+def compiles() -> int:
+    """Programs counted since :func:`count_compiles` was first called."""
+    return int(_COMPILES.value)
 
 # A fixed directory inside the checkout (listed in .gitignore).  The
 # cache key includes the path, so a directory named after a pid, a temp

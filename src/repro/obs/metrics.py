@@ -39,6 +39,7 @@ read surfaces; locked by tests/test_obs.py).
 """
 from __future__ import annotations
 
+import bisect
 import itertools
 import threading
 import weakref
@@ -129,7 +130,7 @@ class Gauge:
 class Histogram:
     """Fixed log2 buckets: upper bounds ``base * 2**i``.  The default
     (1 µs … ~67 s) covers everything from a cache hit to a stuck full
-    scan; ``observe`` is O(log buckets) via binary search."""
+    scan; ``observe`` is O(log buckets) via :func:`bisect.bisect_left`."""
 
     __slots__ = ("__weakref__", "_lock", "bounds", "_counts",
                  "_sum", "_count")
@@ -143,13 +144,7 @@ class Histogram:
         self._count = 0
 
     def observe(self, v: float) -> None:
-        lo, hi = 0, len(self.bounds)
-        while lo < hi:
-            mid = (lo + hi) // 2
-            if v <= self.bounds[mid]:
-                hi = mid
-            else:
-                lo = mid + 1
+        lo = bisect.bisect_left(self.bounds, v)     # first bound >= v
         with self._lock:
             if lo < len(self._counts):
                 self._counts[lo] += 1

@@ -10,6 +10,16 @@ RPC (tagged with the shard address), LSM spill/compaction, each device
 kernel launch — giving one tree per request that shows exactly where
 the budget went.
 
+A **stage** (:func:`stage`) is a span that is always timed: traced or
+not, its duration lands in the ``repro_stage_seconds{stage=<name>}``
+histogram, and under a trace it is also an ordinary child span.  Stages
+are coarse by rule — one per call or per job (the planner's batch, its
+key remap, device fetch and compaction; a job and the phases of a
+PageRank job), never per row or per block — so their label set stays
+a fixed handful of names and their cost (two clock reads, one
+histogram observe, one profiler annotation: ~1-3 µs) stays invisible
+next to the work they time.
+
 Design constraints, in priority order:
 
 1. **The untraced hot path stays O(ns).**  Propagation rides a
@@ -22,10 +32,17 @@ Design constraints, in priority order:
 2. **Bounded memory.**  Finished spans land in a per-:class:`Tracer`
    ring: at most ``max_traces`` traces (LRU-evicted), at most
    ``max_spans`` spans per trace (excess counted, not stored).
-3. **Same-thread propagation only.**  Scans, RPC streams, barriers and
-   kernel launches all execute on the requesting thread, so ContextVar
-   scoping is exactly right; background writer/job threads are *not*
-   in the request's critical path and stay untraced.
+3. **Explicit hand-off across threads.**  Scans, RPC streams, barriers
+   and kernel launches execute on the requesting thread, so ContextVar
+   scoping is exactly right there.  The gateway's job queue runs each
+   job in a copy of its submitter's context
+   (:func:`contextvars.copy_context`), so a traced ``POST /v1/jobs``
+   owns the job's spans too.  Background writer threads stay untraced.
+4. **One clock with the device.**  Once ``jax`` is imported, every
+   stage and every span recorded under a trace also opens a
+   ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+   trace shows them on the host plane beside the device's operations.
+   This module never imports ``jax`` itself.
 
 The tracer doubles as the **slow-query log**: the ``slow_log_size``
 slowest root spans over ``slow_threshold_s`` keep their full span tree
@@ -38,12 +55,16 @@ from __future__ import annotations
 import contextvars
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import OrderedDict
 from typing import Iterable, Optional
 
-__all__ = ["Tracer", "span", "current_ctx", "record", "traced_iter"]
+from .metrics import REGISTRY as _REGISTRY
+
+__all__ = ["Tracer", "span", "stage", "current_ctx", "record",
+           "traced_iter"]
 
 _CTX: "contextvars.ContextVar[Optional[_Ctx]]" = contextvars.ContextVar(
     "repro_trace_ctx", default=None)
@@ -86,16 +107,30 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+def _annotate(name: str):
+    """Open a profiler annotation of ``name`` and return it, or None
+    when ``jax`` was never imported (this module must not import it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class _Span:
     """A live span: context manager that re-parents the ContextVar for
-    its dynamic extent and records itself on exit."""
+    its dynamic extent and records itself on exit.  A stage's span also
+    observes its duration in the stage's histogram."""
 
-    __slots__ = ("_ctx", "name", "tags", "_t0", "_wall0", "_sid", "_token")
+    __slots__ = ("_ctx", "name", "tags", "_t0", "_wall0", "_sid", "_token",
+                 "_hist", "_ann")
 
-    def __init__(self, ctx: _Ctx, name: str, tags: dict):
+    def __init__(self, ctx: _Ctx, name: str, tags: dict, hist=None):
         self._ctx = ctx
         self.name = name
         self.tags = tags
+        self._hist = hist
 
     @property
     def trace_id(self) -> str:
@@ -103,6 +138,7 @@ class _Span:
 
     def __enter__(self):
         ctx = self._ctx
+        self._ann = _annotate(self.name)
         self._sid = ctx.tracer._next_span_id()
         self._wall0 = time.time()
         self._t0 = time.perf_counter()
@@ -115,11 +151,15 @@ class _Span:
     def __exit__(self, et, ev, tb):
         dur = time.perf_counter() - self._t0
         _CTX.reset(self._token)
+        if self._hist is not None:
+            self._hist.observe(dur)
         if et is not None:
             self.tags["error"] = f"{et.__name__}: {ev}"
         ctx = self._ctx
         ctx.tracer._record(ctx.trace_id, self._sid, ctx.span_id,
                            self.name, self._wall0, dur, self.tags)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return False
 
 
@@ -130,6 +170,57 @@ def span(name: str, **tags):
     if ctx is None:
         return _NOOP
     return _Span(ctx, name, tags)
+
+
+_STAGE_SECONDS = _REGISTRY.histogram(
+    "repro_stage_seconds",
+    "Wall time of each program stage (one per call or job)",
+    labels=("stage",))
+# the family holds its children weakly; stages are a fixed handful of
+# names, so each child is pinned here for the life of the process
+_STAGE_HIST: dict = {}
+
+
+class _Stage:
+    """An untraced stage: times itself into its histogram and opens a
+    profiler annotation; records no span."""
+
+    __slots__ = ("name", "_hist", "_t0", "_ann")
+    trace_id = None
+
+    def __init__(self, name: str, hist):
+        self.name = name
+        self._hist = hist
+
+    def __enter__(self):
+        self._ann = _annotate(self.name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def tag(self, **kw) -> None:
+        pass
+
+    def __exit__(self, *exc):
+        self._hist.observe(time.perf_counter() - self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        return False
+
+
+def stage(name: str, **tags):
+    """Open a stage: a span that is timed whether or not a trace is
+    active.  Its duration is observed in
+    ``repro_stage_seconds{stage=name}``; under a trace it is also a
+    child span tagged ``tags``, exactly as :func:`span` would record.
+    Use one per call or per job, never inside a per-row loop."""
+    hist = _STAGE_HIST.get(name)
+    if hist is None:
+        hist = _STAGE_HIST.setdefault(name,
+                                      _STAGE_SECONDS.labels(stage=name))
+    ctx = _CTX.get()
+    if ctx is None:
+        return _Stage(name, hist)
+    return _Span(ctx, name, tags, hist)
 
 
 def record(ctx: Optional[_Ctx], name: str, wall0: float, dur: float,

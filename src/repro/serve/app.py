@@ -41,6 +41,7 @@ from urllib.parse import parse_qs, urlsplit
 
 from ..db.binding import AccidentalDenseError, DBTable
 from ..db.writer import AsyncWriterError
+from ..device import count_compiles
 from ..obs.metrics import REGISTRY
 from ..obs.trace import Tracer
 from .auth import AuthError, TokenAuth
@@ -90,6 +91,7 @@ class Gateway:
         # tracer doubles as the slow-query log (/v1/debug/slow).
         self.trace_sample = float(trace_sample)
         self.tracer = Tracer(slow_threshold_s=slow_threshold_s)
+        count_compiles()            # repro_xla_compiles_total, /v1/stats
         self._http_children: dict = {}      # (route, status) pins
         self._http_lock = threading.Lock()
         self.jobs = JobQueue(n_workers=n_job_workers,

@@ -19,9 +19,15 @@ unboundedly:
 
 Results must already be JSON-serializable — job functions return
 ``to_dict()``-style payloads (see ``repro.serve.routes``).
+
+Each job runs as the stage ``job.<kind>`` (``repro_stage_seconds``), in
+a copy of the submitting thread's context: a job submitted under a
+trace (``POST /v1/jobs?trace=1``) records its spans into that trace,
+and followers coalesced onto a primary share the primary's.
 """
 from __future__ import annotations
 
+import contextvars
 import queue
 import secrets
 import threading
@@ -30,6 +36,7 @@ import weakref
 from typing import Callable, Dict, Optional
 
 from ..obs.metrics import REGISTRY as _REGISTRY, obj_label as _obj_label
+from ..obs.trace import stage as _stage
 from .auth import Tenant
 
 _M_SUBMITTED = _REGISTRY.counter(
@@ -164,7 +171,7 @@ class JobQueue:
                 job.batch_key = batch_key
                 self._coalesce[batch_key] = job
         self._m_submitted.inc()
-        self._q.put((job, fn))
+        self._q.put((job, fn, contextvars.copy_context()))
         return job
 
     def get(self, job_id: str) -> Job:
@@ -189,7 +196,7 @@ class JobQueue:
             item = self._q.get()
             if item is None:
                 return
-            job, fn = item
+            job, fn, ctx = item
             with self._lock:
                 # the drain point: no further followers may attach —
                 # later identical submissions start a fresh primary
@@ -207,7 +214,7 @@ class JobQueue:
                 j.status = "running"
                 j.started_at = self.clock()
             try:
-                result = fn()
+                result = ctx.run(self._run, job.kind, fn)
                 for j in group:
                     j.result = result
                     j.status = "done"
@@ -221,6 +228,11 @@ class JobQueue:
                 now = self.clock()
                 for j in group:
                     j.finished_at = now
+
+    @staticmethod
+    def _run(kind: str, fn: Callable[[], dict]) -> dict:
+        with _stage(f"job.{kind}"):
+            return fn()
 
     def close(self) -> None:
         """Stop the workers; queued-but-unstarted jobs fail fast."""

@@ -367,14 +367,17 @@ def stream_alerts(gw, req: Request):
 @route("GET", "/v1/stats", cost=0.1)
 def stats(gw, req: Request) -> dict:
     """The unified counter snapshot: table (routes/cache/writers/backend)
-    + rate limiter + job queue + the stream's latest windowed sample."""
+    + rate limiter + job queue + device launches and XLA programs built
+    + the stream's latest windowed sample."""
     from ..core.expr import launch_counts
+    from ..device import compiles
     sa = getattr(gw, "stream_analytics", None)
     return {"table": to_jsonable(gw.table.stats()),
             "ratelimit": gw.limiter.stats(),
             "jobs": gw.jobs.stats(),
             "coalesce": gw.coalescer.stats(),
             "kernel_launches": launch_counts(),
+            "xla_compiles": compiles(),
             "trace": gw.tracer.stats(),
             "stream": gw.publisher.latest(),
             "streaming": to_jsonable(sa.stats()) if sa is not None

@@ -209,6 +209,40 @@ class TestEndpoints:
         assert ranks == sorted(ranks, reverse=True)
 
 
+    def test_traced_pagerank_job_owns_its_stages(self, gw):
+        s, d, hdrs = req(gw, "POST", "/v1/jobs?trace=1",
+                         body={"kind": "pagerank",
+                               "params": {"num_iters": 5, "top_k": 5}})
+        assert s == 200
+        tid = hdrs["X-Trace-Id"]
+        assert wait_job(gw, d["job"])["status"] == "done"
+        s, d, _ = get(gw, f"/v1/trace/{tid}")
+        assert s == 200
+        root = d["tree"]
+        assert root["name"] == "POST /v1/jobs"
+        (job,) = [c for c in root["children"] if c["name"] == "job.pagerank"]
+        assert [c["name"] for c in job["children"]] == [
+            "analytics.pagerank.scan", "analytics.pagerank.adjacency",
+            "analytics.pagerank.device"]
+        names = set()
+
+        def walk(node):
+            names.add(node["name"])
+            for c in node["children"]:
+                walk(c)
+        walk(job["children"][0])
+        assert any(n.startswith("db.scan") for n in names)
+
+    def test_stats_count_xla_compiles(self, gw):
+        import jax
+        s, st, _ = get(gw, "/v1/stats")
+        assert s == 200
+        n0 = st["xla_compiles"]
+        jax.jit(lambda x: x * 3.25 + 1.0)(np.ones(7, np.float32))
+        s, st, _ = get(gw, "/v1/stats")
+        assert st["xla_compiles"] >= n0 + 1
+
+
 # ---------------------------------------------------------------------------
 # Error surface: 400/401/404/413/429/503.
 # ---------------------------------------------------------------------------

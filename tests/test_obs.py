@@ -16,7 +16,7 @@ from repro.db import DB, EdgeStore, put
 from repro.db.writer import WriterPool
 from repro.obs.metrics import (Counter, Gauge, Histogram, Registry,
                                REGISTRY, obj_label)
-from repro.obs.trace import Tracer, current_ctx, span, traced_iter
+from repro.obs.trace import Tracer, current_ctx, span, stage, traced_iter
 from repro.serve import Gateway, Tenant, TokenAuth
 from repro.serve.app import synthetic_incidence
 
@@ -257,6 +257,116 @@ class TestTracerUnits:
             assert root.trace_id == "evilid" + "x" * 54
         with tr.start("r", trace_id="!!!") as root:
             assert len(root.trace_id) == 16     # nothing survived: minted
+
+
+# ---------------------------------------------------------------------------
+# Stages: spans that are always timed.
+# ---------------------------------------------------------------------------
+
+def _stage_hist(name):
+    d = REGISTRY.as_dict()
+    key = (("stage", name),)
+    return (d.get(("repro_stage_seconds_count", key), 0),
+            d.get(("repro_stage_seconds_sum", key), 0.0))
+
+
+class TestStages:
+    def test_untraced_stage_observes_its_histogram(self):
+        assert current_ctx() is None
+        n0, s0 = _stage_hist("test.stage.untraced")
+        with stage("test.stage.untraced", k="v") as st:
+            st.tag(more=1)                  # nowhere to go: a no-op
+            time.sleep(0.002)
+        n1, s1 = _stage_hist("test.stage.untraced")
+        assert n1 == n0 + 1
+        assert s1 - s0 >= 0.002
+
+    def test_traced_stage_records_a_parented_span(self):
+        tr = Tracer()
+        n0, _ = _stage_hist("test.stage.traced")
+        with tr.start("root") as root:
+            tid = root.trace_id
+            with span("outer"):
+                with stage("test.stage.traced", k="v"):
+                    with span("inner"):
+                        pass
+        assert _stage_hist("test.stage.traced")[0] == n0 + 1
+        recs = {r["name"]: r for r in tr.spans(tid)}
+        st = recs["test.stage.traced"]
+        assert st["parent_id"] == recs["outer"]["span_id"]
+        assert recs["inner"]["parent_id"] == st["span_id"]
+        assert st["tags"] == {"k": "v"}
+
+    def test_stage_exception_is_tagged_and_observed(self):
+        tr = Tracer()
+        n0, _ = _stage_hist("test.stage.boom")
+        with pytest.raises(RuntimeError):
+            with tr.start("root") as root:
+                tid = root.trace_id
+                with stage("test.stage.boom"):
+                    raise RuntimeError("kaput")
+        with pytest.raises(ValueError):
+            with stage("test.stage.boom"):  # untraced: observed too
+                raise ValueError("no")
+        assert _stage_hist("test.stage.boom")[0] == n0 + 2
+        recs = {r["name"]: r for r in tr.spans(tid)}
+        assert recs["test.stage.boom"]["tags"]["error"] == \
+            "RuntimeError: kaput"
+
+    def test_stage_children_are_pinned(self):
+        with stage("test.stage.pinned"):
+            pass
+        gc.collect()
+        assert _stage_hist("test.stage.pinned")[0] >= 1
+
+    def test_stages_are_annotated_on_the_profiler_host_plane(self,
+                                                             tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+        tr = Tracer()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with stage("test.stage.annotated"):
+                jax.numpy.ones(4).block_until_ready()
+            with tr.start("test.root.annotated"):
+                with span("test.span.annotated"):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        names = {e.name for p in ProfileData.from_file(str(path)).planes
+                 if p.name.startswith("/host") for ln in p.lines
+                 for e in ln.events}
+        assert {"test.stage.annotated", "test.root.annotated",
+                "test.span.annotated"} <= names
+
+    def test_obs_imports_without_jax(self):
+        import subprocess
+        import sys
+        code = ("import sys\n"
+                "class NoJax:\n"
+                "    def find_spec(self, name, path=None, target=None):\n"
+                "        if name == 'jax' or name.startswith('jax.'):\n"
+                "            raise ImportError('jax is blocked')\n"
+                "sys.meta_path.insert(0, NoJax())\n"
+                "import repro.obs\n"
+                "from repro.obs import stage, Tracer\n"
+                "with stage('x'):\n"
+                "    pass\n"
+                "with Tracer().start('r'):\n"
+                "    with stage('y'):\n"
+                "        pass\n"
+                "assert 'jax' not in sys.modules\n"
+                "print('ok')\n")
+        import os
+        import repro.obs
+        src = os.path.dirname(os.path.dirname(
+            os.path.dirname(repro.obs.__file__)))
+        env = dict(os.environ, PYTHONPATH=src)
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=60)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip() == "ok"
 
 
 # ---------------------------------------------------------------------------
