@@ -132,9 +132,10 @@ def pagerank_table(T, mesh: Mesh | None = None, num_iters: int = 20,
 
     Queries the src/dst column blocks through the :class:`DBTable`
     selection grammar (pushed-down transpose-table scans), builds the
-    host adjacency, then runs the mesh-sharded PageRank on the device
-    payload.  Returns ``(node_keys, ranks)`` aligned by index, the ranks
-    a float32 host array.  The three phases are the stages
+    square adjacency on the host (:func:`graph.adjacency_bands`), then
+    runs the mesh-sharded PageRank on the device payload.  Returns
+    ``(node_keys, ranks)`` aligned by index, the ranks a float32 host
+    array.  The three phases are the stages
     ``analytics.pagerank.scan``, ``.adjacency`` and ``.device``.
 
     ``T`` may equally be an in-memory incidence :class:`Assoc` (a
@@ -151,8 +152,8 @@ def pagerank_table(T, mesh: Mesh | None = None, num_iters: int = 20,
         src, dst = eval_batch([T[:, f"{src_field}{sep}*,"],
                                T[:, f"{dst_field}{sep}*,"]])
     with stage("analytics.pagerank.adjacency"):
-        adj = graph.square(graph.adjacency(
-            src + dst, src_field=src_field, dst_field=dst_field, sep=sep))
+        adj = graph.adjacency_bands(src, dst, src_field=src_field,
+                                    dst_field=dst_field, sep=sep)
     if adj.nnz == 0:
         return np.empty((0,), dtype=str), np.zeros((0,), np.float32)
     if reverse:

@@ -18,9 +18,20 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+import scipy.sparse as sp
 
 from .assoc import Assoc, StartsWith
 from . import sparse as S
+from ..obs.metrics import REGISTRY
+
+_BUILDS = REGISTRY.counter(
+    "repro_adjacency_builds_total",
+    "Square adjacencies built from a src band and a dst band "
+    "(adjacency_bands), by how the bands' rows were aligned",
+    labels=("rows",))
+# the family holds children weakly, so pin them
+_BUILT = {rows: _BUILDS.labels(rows=rows)
+          for rows in ("shared", "intersected")}
 
 
 # ---------------------------------------------------------------------------
@@ -47,6 +58,58 @@ def square(A: Assoc) -> Assoc:
     before spectral/PageRank work on a directed adjacency)."""
     nodes = np.union1d(A.row, A.col)
     sm = A._numeric_sm_promoted(nodes, nodes)
+    return Assoc._from_parts(nodes, nodes, None, sm)
+
+
+def _field_block(E: Assoc, prefix: str):
+    """(column keys, payload) of the columns of ``E`` under ``prefix``."""
+    ci = StartsWith(prefix).mask(E.col)
+    if ci.all():
+        return E.col, E.sm
+    return E.col[ci], E.sm[:, ci]
+
+
+def adjacency_bands(Esrc: Assoc, Edst: Assoc, src_field: str = "ip.src",
+                    dst_field: str = "ip.dst", sep: str = "|") -> Assoc:
+    """``square(adjacency(Esrc + Edst))`` built on integer indices.
+
+    ``Esrc`` holds the ``src_field|*`` columns of an incidence array and
+    ``Edst`` its ``dst_field|*`` columns (any other column is ignored).
+    The product runs on the payloads' indices; keys are touched once per
+    distinct vertex, never per entry.  Same node keys, values and shape
+    as the D4M listing, which stays the reference.
+    """
+    skeys, a = _field_block(Esrc, f"{src_field}{sep}")
+    dkeys, b = _field_block(Edst, f"{dst_field}{sep}")
+    if Esrc.val is not None or Edst.val is not None:
+        # the sum of the bands is categorical, which ``*`` views as logical
+        a, b = a.copy(), b.copy()
+        a.data = np.ones_like(a.data)
+        b.data = np.ones_like(b.data)
+    if np.array_equal(Esrc.row, Edst.row):
+        _BUILT["shared"].inc()
+    else:
+        _, ia, ib = np.intersect1d(Esrc.row, Edst.row, assume_unique=True,
+                                   return_indices=True)
+        a, b = a[ia], b[ib]
+        _BUILT["intersected"].inc()
+    P = (a.T.tocsr() @ b).tocsr()   # src columns x dst columns
+    P.eliminate_zeros()
+    # only vertices holding an entry are nodes
+    used_r = np.flatnonzero(np.diff(P.indptr))
+    used_c = np.flatnonzero(np.bincount(P.indices, minlength=P.shape[1]))
+    strip = len(src_field) + len(sep)
+    stripd = len(dst_field) + len(sep)
+    src = np.asarray([k[strip:] for k in skeys[used_r]], dtype=str)
+    dst = np.asarray([k[stripd:] for k in dkeys[used_c]], dtype=str)
+    nodes = np.union1d(src, dst)
+    rmap = np.zeros(P.shape[0], np.int64)
+    rmap[used_r] = np.searchsorted(nodes, src)
+    cmap = np.zeros(P.shape[1], np.int64)
+    cmap[used_c] = np.searchsorted(nodes, dst)
+    coo = P.tocoo()
+    sm = sp.csr_matrix((coo.data, (rmap[coo.row], cmap[coo.col])),
+                       shape=(nodes.shape[0], nodes.shape[0]))
     return Assoc._from_parts(nodes, nodes, None, sm)
 
 
