@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..core.expr import count_launch
 from ..core.sparse import COO
 from ..device import count_h2d
 from ..obs.trace import stage
@@ -204,3 +205,122 @@ def spmv_weighted_rowsum(m: COO, mesh: Mesh, axis: str = "data"
         return jax.lax.psum(local, axis)
 
     return _rs(sh.rows, sh.cols, sh.vals)
+
+
+# ---------------------------------------------------------------------------
+# Community detection by label propagation (LDBC Graphalytics CDLP).
+# ---------------------------------------------------------------------------
+
+def shard_by_vertex(indptr: np.ndarray, indices: np.ndarray,
+                    n_shards: int) -> tuple:
+    """Split a CSR's entries into ``n_shards`` row-contiguous blocks of
+    about equal nnz that never split a row (a vertex's pairs stay on one
+    shard), padded with dead entries at row == nrows as :func:`shard_coo`
+    pads.  Returns host arrays ``(rows, cols)``, each
+    ``(n_shards, per)``."""
+    n = indptr.shape[0] - 1
+    nnz = int(indptr[-1])
+    cut = np.searchsorted(indptr, np.arange(n_shards + 1) * nnz / n_shards)
+    cut[-1] = n                     # trailing rows without pairs
+    lo, hi = indptr[cut[:-1]], indptr[cut[1:]]
+    per = max(int((hi - lo).max()), 1)
+    rows = np.full((n_shards, per), n, np.int32)
+    cols = np.zeros((n_shards, per), np.int32)
+    deg = np.diff(indptr)
+    for k in range(n_shards):
+        r0, r1 = cut[k], cut[k + 1]
+        m = hi[k] - lo[k]
+        rows[k, :m] = np.repeat(np.arange(r0, r1), deg[r0:r1])
+        cols[k, :m] = indices[lo[k]:hi[k]]
+    return rows, cols
+
+
+def cdlp_sharded(adj, mesh: Mesh, num_iters: int = 10) -> jax.Array:
+    """Community detection by label propagation over an undirected graph
+    (``adj``: a symmetric scipy CSR pattern without diagonal, as
+    :func:`graph.undirected_bands` builds).  Each vertex starts with its
+    own index as label; in each of ``num_iters`` synchronous iterations
+    every vertex takes the label most frequent among its neighbours,
+    ties to the smallest, and a vertex with no neighbours keeps its own.
+    The pairs are sharded by owning vertex over the mesh axis ``data``
+    (:func:`shard_by_vertex`).  Returns the int32 labels."""
+    sharding = NamedSharding(mesh, P("data", None))
+    rows, cols = (jax.device_put(b, sharding) for b in
+                  shard_by_vertex(adj.indptr, adj.indices,
+                                  mesh.shape["data"]))
+    count_h2d("cdlp", rows, cols)
+    count_launch("cdlp")
+    return _cdlp(rows, cols, n=adj.shape[0], mesh=mesh,
+                 num_iters=num_iters)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "mesh", "num_iters"))
+def _cdlp(rows: jax.Array, cols: jax.Array, *, n: int, mesh: Mesh,
+          num_iters: int) -> jax.Array:
+    """Every iteration as one program: gather the neighbours' labels,
+    sort each shard's pairs by (vertex, label), count each run, keep per
+    vertex the first label of the longest run, and combine the shards'
+    disjoint vertex blocks with ``psum``."""
+    spec = P("data", None)
+
+    @functools.partial(
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec),
+        out_specs=P(), check_vma=False)
+    def _run(rows, cols):
+        rows, cols = rows[0], cols[0]
+
+        def by_vertex(op, x):
+            # segment n gathers the dead entries
+            return op(x, rows, num_segments=n + 1,
+                      indices_are_sorted=True)[:n]
+
+        pos = jnp.arange(rows.shape[0], dtype=jnp.int32)
+        first = jnp.ones((1,), bool)
+        row_start = jnp.concatenate([first, rows[1:] != rows[:-1]])
+        mine = by_vertex(jax.ops.segment_sum, jnp.ones_like(rows)) > 0
+        has_pairs = jax.lax.psum(mine.astype(jnp.int32), "data") > 0
+
+        def step(_, labels):
+            _, lab = jax.lax.sort((rows, labels[cols]), num_keys=2)
+            run_start = row_start | jnp.concatenate([first,
+                                                     lab[1:] != lab[:-1]])
+            # pairs so far in each (vertex, label) run: a vertex's largest
+            # is its longest run, and that run's last pair is the first
+            # to reach it in label order
+            count = pos - jax.lax.cummax(jnp.where(run_start, pos, 0)) + 1
+            most = by_vertex(jax.ops.segment_max, count)
+            tied = jnp.where(count == most[jnp.minimum(rows, n - 1)], lab, n)
+            mode = by_vertex(jax.ops.segment_min, tied)
+            return jnp.where(has_pairs, jax.lax.psum(
+                jnp.where(mine, mode, 0), "data"), labels)
+
+        return jax.lax.fori_loop(0, num_iters, step,
+                                 jnp.arange(n, dtype=jnp.int32))
+
+    return _run(rows, cols)
+
+
+def cdlp_table(T, mesh: Mesh | None = None, num_iters: int = 10
+               ) -> tuple[np.ndarray, np.ndarray]:
+    """CDLP served straight from the database binding, as
+    :func:`pagerank_table`: the two band scans (``ip.src|*`` and
+    ``ip.dst|*``), the undirected simple
+    graph built on the host (:func:`graph.undirected_bands`), then
+    :func:`cdlp_sharded` on the mesh.  Returns ``(node_keys, labels)``
+    aligned by index; a label is the index of a node in ``node_keys``.
+    The three phases are the stages ``analytics.cdlp.scan``,
+    ``.adjacency`` and ``.device`` (upload, program, labels home)."""
+    from ..core import graph
+    from ..core.expr import eval_batch
+
+    with stage("analytics.cdlp.scan"):
+        src, dst = eval_batch([T[:, "ip.src|*,"], T[:, "ip.dst|*,"]])
+    with stage("analytics.cdlp.adjacency"):
+        und = graph.undirected_bands(src, dst)
+    if und.shape[0] == 0:
+        return np.empty((0,), dtype=str), np.zeros((0,), np.int32)
+    if mesh is None:
+        mesh = Mesh(np.asarray(jax.devices()), ("data",))
+    with stage("analytics.cdlp.device", nnz=und.nnz):
+        labels = np.asarray(cdlp_sharded(und.sm, mesh, num_iters=num_iters))
+    return und.row, labels

@@ -64,7 +64,13 @@ _KERNEL_LAUNCH_FAMILY = _REGISTRY.counter(
 _KERNEL_COUNTERS = {
     "spmv": _KERNEL_LAUNCH_FAMILY.labels(kernel="spmv"),
     "spmm": _KERNEL_LAUNCH_FAMILY.labels(kernel="spmm"),
+    "cdlp": _KERNEL_LAUNCH_FAMILY.labels(kernel="cdlp"),
 }
+
+
+def count_launch(kernel: str) -> None:
+    """Count one launch of ``kernel`` (a key of :func:`launch_counts`)."""
+    _KERNEL_COUNTERS[kernel].inc()
 
 
 def launch_counts() -> dict:

@@ -113,6 +113,22 @@ def adjacency_bands(Esrc: Assoc, Edst: Assoc, src_field: str = "ip.src",
     return Assoc._from_parts(nodes, nodes, None, sm)
 
 
+def undirected_bands(Esrc: Assoc, Edst: Assoc) -> Assoc:
+    """The undirected simple graph of the ``ip.src|`` / ``ip.dst|``
+    bands' edges, on the nodes of :func:`adjacency_bands`: each unordered
+    pair {u, v} with u != v that appears in either direction is one
+    entry each way (value 1); duplicates merge and self-loops drop, so a
+    vertex whose only edges are self-loops stays a node with no
+    neighbours (LDBC Graphalytics' undirected graph)."""
+    A = adjacency_bands(Esrc, Edst)
+    sm = (A.sm + A.sm.T).tocsr()
+    sm = sm - sp.diags(sm.diagonal(), format="csr")
+    sm.eliminate_zeros()
+    sm.data[:] = 1.0
+    sm.sort_indices()
+    return Assoc._from_parts(A.row, A.col, None, sm)
+
+
 def connections(E: Assoc, ip: str, src_field: str = "ip.src",
                 dst_field: str = "ip.dst", sep: str = "|") -> Assoc:
     """Fig. 2's operation: every host that ``ip`` talked to (either

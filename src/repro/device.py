@@ -22,7 +22,7 @@ _H2D_BYTES = REGISTRY.counter(
     "the device", labels=("site",))
 # the sites are fixed; the family holds children weakly, so pin them
 _H2D = {site: _H2D_BYTES.labels(site=site)
-        for site in ("coo", "dense", "pagerank")}
+        for site in ("coo", "dense", "pagerank", "cdlp")}
 _COMPILES = REGISTRY.counter(
     "repro_xla_compiles_total",
     "XLA programs built by this process: compiled, or loaded from the "
@@ -34,7 +34,7 @@ _compiles_watched = False
 
 def count_h2d(site: str, *arrays) -> None:
     """Count ``arrays``, just placed on the device, as bytes handed over
-    at ``site`` (``coo``, ``dense`` or ``pagerank``)."""
+    at ``site`` (``coo``, ``dense``, ``pagerank`` or ``cdlp``)."""
     _H2D[site].inc(sum(int(a.nbytes) for a in arrays))
 
 

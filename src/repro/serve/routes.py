@@ -20,6 +20,7 @@ Error surface (mapped by the app):
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
@@ -236,6 +237,12 @@ def _job_fns(gw, params: dict) -> Dict[str, Callable[[], dict]]:
                           for i in order],
                 "n_nodes": int(ranks.shape[0])}
 
+    def cdlp() -> dict:
+        from ..analytics.distributed import cdlp_table
+        keys, labels = cdlp_table(
+            gw.table, num_iters=int(params.get("num_iters", 10)))
+        return _cdlp_summary(keys, labels, int(params.get("top_k", 20)))
+
     def degree_fit_full() -> dict:
         fit = fit_degree_table(gw.table, params.get("prefix", "ip.dst|"))
         return {"fit": fit.to_dict()}
@@ -263,9 +270,25 @@ def _job_fns(gw, params: dict) -> Dict[str, Callable[[], dict]]:
                             num_iters=int(params.get("num_iters", 30)))
         return {"report": rep.to_dict()}
 
-    return {"pagerank": pagerank, "degree_fit": degree_fit_full,
+    return {"pagerank": pagerank, "cdlp": cdlp,
+            "degree_fit": degree_fit_full,
             "c2": c2_sweep, "scanners": scan_sweep,
             "root_cause": root_cause_job}
+
+
+def _cdlp_summary(keys: np.ndarray, labels: np.ndarray, top_k: int) -> dict:
+    """A CDLP job's result: the node and community counts, the ``top_k``
+    largest communities (by size, then key; each named by its label's
+    node key), and the sha256 of the labels as little-endian int32 in
+    node-key order, which stands for every vertex's label."""
+    sizes = np.bincount(labels, minlength=keys.shape[0])
+    comm = np.flatnonzero(sizes)
+    top = comm[np.lexsort((comm, -sizes[comm]))[:top_k]]
+    digest = hashlib.sha256(labels.astype("<i4").tobytes()).hexdigest()
+    return {"n_nodes": int(keys.shape[0]), "n_communities": int(comm.size),
+            "communities": [{"key": str(keys[i]), "size": int(sizes[i])}
+                            for i in top],
+            "labels_sha256": digest}
 
 
 @route("POST", "/v1/jobs", cost=2.0)

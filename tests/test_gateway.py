@@ -233,6 +233,39 @@ class TestEndpoints:
         walk(job["children"][0])
         assert any(n.startswith("db.scan") for n in names)
 
+    def test_cdlp_job(self, gw, capture):
+        """``cdlp`` round-trips with the documented result, equal to the
+        dict reference over the capture's edges, and owns its stages."""
+        import hashlib
+        from _graphalytics_ref import cdlp, neighbours
+        s, d, hdrs = req(gw, "POST", "/v1/jobs?trace=1",
+                         body={"kind": "cdlp",
+                               "params": {"num_iters": 3, "top_k": 5}})
+        assert s == 200
+        assert wait_job(gw, d["job"])["status"] == "done"
+        s, res, _ = get(gw, f"/v1/jobs/{d['job']}/result")
+        assert s == 200 and res["kind"] == "cdlp"
+        got = res["result"]
+        src = dict(zip(*capture[:, "ip.src|*,"].triples()[:2]))
+        dst = dict(zip(*capture[:, "ip.dst|*,"].triples()[:2]))
+        pairs = [(src[r][7:], dst[r][7:]) for r in src if r in dst]
+        vertices = sorted({x for e in pairs for x in e})
+        rank = {v: i for i, v in enumerate(vertices)}
+        lab = cdlp(neighbours(vertices, pairs), 3)
+        want = np.asarray([rank[lab[v]] for v in vertices], "<i4")
+        sizes = {v: int((want == rank[v]).sum()) for v in vertices}
+        comm = sorted((k for k in sizes if sizes[k]),
+                      key=lambda k: (-sizes[k], k))
+        assert got == {
+            "n_nodes": len(vertices), "n_communities": len(comm),
+            "communities": [{"key": k, "size": sizes[k]} for k in comm[:5]],
+            "labels_sha256": hashlib.sha256(want.tobytes()).hexdigest()}
+        s, t, _ = get(gw, f"/v1/trace/{hdrs['X-Trace-Id']}")
+        (job,) = [c for c in t["tree"]["children"] if c["name"] == "job.cdlp"]
+        assert [c["name"] for c in job["children"]] == [
+            "analytics.cdlp.scan", "analytics.cdlp.adjacency",
+            "analytics.cdlp.device"]
+
     def test_stats_count_xla_compiles(self, gw):
         import jax
         s, st, _ = get(gw, "/v1/stats")

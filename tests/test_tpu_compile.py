@@ -113,3 +113,15 @@ def test_pagerank_compiles_for_four_chips(topo):
                             mesh=mesh, num_iters=20, damping=0.85,
                             axis="data").compile().as_text()
     assert "all-reduce" in txt
+
+
+def test_cdlp_compiles_for_four_chips(topo):
+    """The CDLP label program over a 4-device mesh: each chip sorts and
+    reduces its own vertices' pairs, and the labels are combined with an
+    all-reduce every iteration."""
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("data",))
+    blocks = NamedSharding(mesh, P("data", None))
+    pairs = _shape(blocks, (4, 4096), jnp.int32)
+    txt = D._cdlp.lower(pairs, pairs, n=1024, mesh=mesh,
+                        num_iters=10).compile().as_text()
+    assert "all-reduce" in txt and "sort" in txt
