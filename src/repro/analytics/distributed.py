@@ -258,9 +258,10 @@ def cdlp_sharded(adj, mesh: Mesh, num_iters: int = 10) -> jax.Array:
 def _cdlp(rows: jax.Array, cols: jax.Array, *, n: int, mesh: Mesh,
           num_iters: int) -> jax.Array:
     """Every iteration as one program: gather the neighbours' labels,
-    sort each shard's pairs by (vertex, label), count each run, keep per
-    vertex the first label of the longest run, and combine the shards'
-    disjoint vertex blocks with ``psum``."""
+    sort each shard's pairs by (vertex, label), count each run, read per
+    vertex the first label of its longest run at the vertex's last pair,
+    and combine the shards' disjoint vertex blocks with ``psum``.  The
+    loop reads the sorted order by position and never scatters."""
     spec = P("data", None)
 
     @functools.partial(
@@ -268,29 +269,32 @@ def _cdlp(rows: jax.Array, cols: jax.Array, *, n: int, mesh: Mesh,
         out_specs=P(), check_vma=False)
     def _run(rows, cols):
         rows, cols = rows[0], cols[0]
-
-        def by_vertex(op, x):
-            # segment n gathers the dead entries
-            return op(x, rows, num_segments=n + 1,
-                      indices_are_sorted=True)[:n]
-
         pos = jnp.arange(rows.shape[0], dtype=jnp.int32)
         first = jnp.ones((1,), bool)
         row_start = jnp.concatenate([first, rows[1:] != rows[:-1]])
-        mine = by_vertex(jax.ops.segment_sum, jnp.ones_like(rows)) > 0
+        # the sorts keep rows, which is sorted already (the dead entries at
+        # row n last), so each vertex's pairs keep their positions: seg is
+        # each pair's vertex's first, end each vertex's last
+        vertex = jnp.arange(n, dtype=rows.dtype)
+        end = jnp.maximum(jnp.searchsorted(rows, vertex, side="right") - 1, 0)
+        mine = rows[end] == vertex
+        seg = jax.lax.cummax(jnp.where(row_start, pos, 0))
         has_pairs = jax.lax.psum(mine.astype(jnp.int32), "data") > 0
 
         def step(_, labels):
             _, lab = jax.lax.sort((rows, labels[cols]), num_keys=2)
             run_start = row_start | jnp.concatenate([first,
                                                      lab[1:] != lab[:-1]])
-            # pairs so far in each (vertex, label) run: a vertex's largest
-            # is its longest run, and that run's last pair is the first
-            # to reach it in label order
+            # pairs so far in each (vertex, label) run
             count = pos - jax.lax.cummax(jnp.where(run_start, pos, 0)) + 1
-            most = by_vertex(jax.ops.segment_max, count)
-            tied = jnp.where(count == most[jnp.minimum(rows, n - 1)], lab, n)
-            mode = by_vertex(jax.ops.segment_min, tied)
+            # seg + count exceeds every earlier vertex's, so its running
+            # maximum is each vertex's own longest run so far; the last
+            # pair that raised it is the first, in label order, to reach
+            # the vertex's longest run: ties go to the smallest label
+            most = jax.lax.cummax(seg + count)
+            raised = most > jnp.concatenate([jnp.full((1,), -1, most.dtype),
+                                             most[:-1]])
+            mode = lab[jax.lax.cummax(jnp.where(raised, pos, 0))[end]]
             return jnp.where(has_pairs, jax.lax.psum(
                 jnp.where(mine, mode, 0), "data"), labels)
 

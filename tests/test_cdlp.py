@@ -2,6 +2,7 @@
 the undirected simple graph built from two bands, and the device
 program's labels, against the plain dict reference on seeded graphs."""
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -42,6 +43,15 @@ def _edges_self_loops():
     return [(9, 9), (1, 1), (1, 2), (2, 3), (3, 3), (3, 1), (5, 6)]
 
 
+def _edges_tied_runs():
+    # in the second iteration hub 50 sees the labels 40, 40, 35, 35, 30, 30
+    # in neighbour order (a three-way tie, the largest label first), and
+    # 60 sees 1 once and 20 twice (the smallest label is not the mode)
+    leaves = [(10, 40), (11, 40), (12, 35), (13, 35), (14, 30), (15, 30)]
+    return ([(50, a) for a, _ in leaves] + leaves
+            + [(60, 2), (60, 21), (60, 22), (2, 1), (21, 20), (22, 20)])
+
+
 def _edges_kronecker(seed=5300000016):
     u, v = edges(seed, 7)
     return list(zip(u.tolist(), v.tolist()))
@@ -52,6 +62,7 @@ GRAPHS = {"random_0": lambda: _edges_random(0),
           "ties": _edges_ties,
           "reciprocal_duplicates": _edges_reciprocal_and_duplicates,
           "self_loops": _edges_self_loops,
+          "tied_runs": _edges_tied_runs,
           "kronecker": _edges_kronecker}
 
 
@@ -87,11 +98,19 @@ def test_labels_equal_the_dict_reference(case, num_iters):
 
 
 def test_cases_read_as_meant():
-    """The tie rule decides the ties case, and a self-loop alone leaves a
-    vertex with no neighbours."""
+    """The tie rule decides the ties case, in the second iteration the
+    tied_runs case's hub takes the smallest of three tied labels and 60
+    the label of its longest run, and a self-loop alone leaves a vertex
+    with no neighbours."""
     want, vertices = _want(_edges_ties(), 1)
     assert want[vertices.index(0)] == vertices.index(1)
     assert want[vertices.index(4)] == vertices.index(5)
+    want, vertices = _want(_edges_tied_runs(), 2)
+    got = D.cdlp_table(_bands(_edges_tied_runs())[0], mesh=_mesh(),
+                       num_iters=2)[1]
+    for v, mode in ((50, 30), (60, 20)):
+        assert want[vertices.index(v)] == got[vertices.index(v)] \
+            == vertices.index(mode)
     E, src, dst = _bands(_edges_self_loops())
     U = graph.undirected_bands(src, dst)
     nine = list(U.row).index("000009")
@@ -151,6 +170,40 @@ def test_label_program_compiles_once():
     b = D.cdlp_sharded(U.copy(), _mesh(), num_iters=4)
     assert D._cdlp._cache_size() == before + 1
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _loop_text(hlo: str) -> str:
+    """The StableHLO the ``while`` loops run: each loop's body region and
+    every function it calls, transitively."""
+    funcs = {m.group(1): m.group(0) for m in re.finditer(
+        r"^  func\.func \w+ @([\w.$-]+)\(.*?^  }$", hlo, re.S | re.M)}
+    parts = []
+    for m in re.finditer(r"\} do \{", hlo):
+        depth, i = 1, m.end()
+        while depth:
+            depth += {"{": 1, "}": -1}.get(hlo[i], 0)
+            i += 1
+        parts.append(hlo[m.end():i])
+    seen = set()
+    for text in parts:
+        for name in re.findall(r"call @([\w.$-]+)", text):
+            if name not in seen:
+                seen.add(name)
+                parts.append(funcs[name])
+    return "\n".join(parts)
+
+
+def test_label_loop_has_no_scatter():
+    """Each iteration finds every vertex's mode from sorted order: the
+    loop holds sorts and gathers but no scatter."""
+    _, src, dst = _bands(_edges_random(0))
+    U = graph.undirected_bands(src, dst).sm
+    rows, cols = D.shard_by_vertex(U.indptr, U.indices, 1)
+    hlo = D._cdlp.lower(rows, cols, n=U.shape[0], mesh=_mesh(),
+                        num_iters=3).as_text()
+    loop = _loop_text(hlo)
+    assert "stablehlo.sort" in loop and "stablehlo.gather" in loop
+    assert "scatter" not in loop
 
 
 def test_vertex_blocks_never_split_a_row():
